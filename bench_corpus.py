@@ -28,7 +28,9 @@ CREATION_FIXTURES = {
 }
 
 
-def analyze_one(path: Path, timeout: int, tpu_lanes: int = 0):
+def analyze_report(path: Path, timeout: int, tpu_lanes: int = 0):
+    """(report, wall seconds) of the corpus analysis of one fixture:
+    every detector, two transactions, bfs."""
     from mythril_tpu.orchestration.mythril_analyzer import MythrilAnalyzer
     from mythril_tpu.orchestration.mythril_disassembler import (
         MythrilDisassembler,
@@ -48,7 +50,11 @@ def analyze_one(path: Path, timeout: int, tpu_lanes: int = 0):
     )
     t0 = time.perf_counter()
     report = analyzer.fire_lasers(modules=None, transaction_count=2)
-    elapsed = time.perf_counter() - t0
+    return report, time.perf_counter() - t0
+
+
+def analyze_one(path: Path, timeout: int, tpu_lanes: int = 0):
+    report, elapsed = analyze_report(path, timeout, tpu_lanes)
     issues = report.sorted_issues()
     return {
         "contract": path.name,

@@ -41,22 +41,6 @@ from .ops import Call, VarType, get_variable
 log = logging.getLogger(__name__)
 
 
-def _device_exec_ok() -> bool:
-    """If the sweep would bail at runtime, the host path must not
-    silently run without the pruner (support.devices.device_exec_ok —
-    one executed-op probe per process, importable lane engine)."""
-    try:
-        from ..laser.lane_engine import LaneEngine  # noqa: F401
-        from ..support.devices import device_exec_ok
-
-        if device_exec_ok():
-            return True
-        log.warning("lane engine unavailable; host pruners kept")
-    except Exception as e:
-        log.warning("lane engine unavailable (%s); host pruners kept", e)
-    return False
-
-
 class SymExecWrapper:
     """Symbolically executes the code and pre-parses the statespace."""
 
@@ -161,9 +145,11 @@ class SymExecWrapper:
         # will actually run — and kept when a selected module pins JUMPI
         # to the host (no lane adapter), which idles the sweep
         # (svm._lane_engine_sweep) and pruning is all the help we get
-        from ..support.devices import effective_tpu_lanes
+        from ..support.devices import effective_tpu_lanes, require_device
 
-        lane_engine_active = bool(effective_tpu_lanes()) \
+        lanes = effective_tpu_lanes()
+        require_device(lanes)
+        lane_engine_active = bool(lanes) \
             and not args.use_issue_annotations
         if lane_engine_active and run_analysis_modules:
             # mirror of svm._lane_engine_sweep's hook gate: a module
@@ -182,35 +168,6 @@ class SymExecWrapper:
                 if ad is None or "JUMPI" not in ad.lifted_hooks:
                     lane_engine_active = False
                     break
-        if lane_engine_active and not _device_exec_ok():
-            lane_engine_active = False
-        if lane_engine_active:
-            # mirror of the sweep's link-aware engagement gate
-            # (lane_engine.device_break_even): on a tunneled backend a
-            # contract not known to fork wide will have its small
-            # waves declined anyway — dropping the dependency pruner
-            # for such a run would be the worst of both (no device, no
-            # pruning). Keep the pruner; its JUMPI hook idles the
-            # sweep, which is exactly the routing the gate would pick.
-            try:
-                from ..laser.lane_engine import (
-                    code_to_bytes,
-                    device_break_even,
-                )
-
-                code_bytes = code_to_bytes(contract.disassembly)
-                if (
-                    code_bytes is not None
-                    and device_break_even(code_bytes) > 1
-                ):
-                    # PATH_HISTORY for this code also fills from HOST
-                    # exploration (svm records the worklist peak), so
-                    # an in-process re-analysis of a wide-forking
-                    # contract flips this decision — no bootstrap
-                    # deadlock with the pruner
-                    lane_engine_active = False
-            except Exception:
-                pass  # unknown code shape: keep lane routing as-is
         if not disable_dependency_pruning and not lane_engine_active:
             plugin_loader.load(DependencyPrunerBuilder())
         elif lane_engine_active:

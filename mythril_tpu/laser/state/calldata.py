@@ -35,7 +35,12 @@ class BaseCalldata:
 
     def get_word_at(self, offset: int) -> BitVec:
         """32-byte big-endian word at byte offset."""
-        parts = self[offset : offset + 32]
+        if isinstance(offset, BitVec) and offset.value is None:
+            # symbolic offset: the slice cannot enumerate it, but each
+            # of the 32 bytes from it on loads on its own
+            parts = [self._load(simplify(offset + i)) for i in range(32)]
+        else:
+            parts = self[offset : offset + 32]
         return simplify(Concat(parts))
 
     def __getitem__(self, item: Union[int, slice, BitVec]) -> Any:

@@ -8,6 +8,7 @@ ctypes (no pybind11 in this environment).
 """
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -17,19 +18,47 @@ log = logging.getLogger(__name__)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_HERE, "_native.so")
+#: the committed inputs of the build; their hash, kept beside the .so,
+#: decides a rebuild (file times do not survive a copy of the tree)
+_SOURCES = ("Makefile", "sat.cpp", "keccak.cpp", "blaster.cpp")
+_HASH_PATH = _LIB_PATH + ".sha256"
 _lock = threading.Lock()
 _lib = None
 
 
-def _build() -> None:
+def _sources_hash() -> str:
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        with open(os.path.join(_HERE, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read() + b"\0")
+    return h.hexdigest()
+
+
+def _built_hash() -> str:
+    try:
+        with open(_HASH_PATH) as f:
+            return f.read().strip()
+    except OSError:
+        return ""
+
+
+def _build(digest: str) -> None:
+    """Build into a private name, then rename into place: processes
+    that import the package concurrently never load a partial .so."""
+    tmp = f"_native.so.{os.getpid()}.tmp"
     proc = subprocess.run(
-        ["make", "-s"], cwd=_HERE, capture_output=True, text=True
+        ["make", "-s", "-B", f"TARGET={tmp}"], cwd=_HERE,
+        capture_output=True, text=True
     )
     if proc.returncode != 0:
         raise RuntimeError(
             f"native build failed (exit {proc.returncode}):\n"
             f"{proc.stdout}\n{proc.stderr}"
         )
+    os.replace(os.path.join(_HERE, tmp), _LIB_PATH)
+    with open(_HASH_PATH + f".{os.getpid()}.tmp", "w") as f:
+        f.write(digest + "\n")
+    os.replace(_HASH_PATH + f".{os.getpid()}.tmp", _HASH_PATH)
 
 
 def get_lib() -> ctypes.CDLL:
@@ -40,8 +69,9 @@ def get_lib() -> ctypes.CDLL:
     with _lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH) or _needs_rebuild():
-            _build()
+        digest = _sources_hash()
+        if not os.path.exists(_LIB_PATH) or _built_hash() != digest:
+            _build(digest)
         lib = ctypes.CDLL(_LIB_PATH)
         lib.mtpu_keccak256.argtypes = [
             ctypes.c_char_p,
@@ -144,14 +174,6 @@ def get_lib() -> ctypes.CDLL:
             )
         _lib = lib
         return _lib
-
-
-def _needs_rebuild() -> bool:
-    so_mtime = os.path.getmtime(_LIB_PATH)
-    for src in ("sat.cpp", "keccak.cpp", "blaster.cpp"):
-        if os.path.getmtime(os.path.join(_HERE, src)) > so_mtime:
-            return True
-    return False
 
 
 def keccak256(data: bytes) -> bytes:

@@ -76,8 +76,7 @@ _BINOP_MAP = {
 # made every structurally-new DAG a cold compile: level widths repeat
 # (pow2-padded) but the node-table row count and the exact opcode subset
 # of each level varied per contract, so a corpus sweep re-specialized
-# near-identical kernels dozens of times (a tunneled wave measured 50 s
-# in one compile — see models/pruner.py). Two canonicalizations collapse
+# near-identical kernels dozens of times. Two canonicalizations collapse
 # the key space:
 #
 # 1. the node table pads to a power of two, so table shapes bucket the

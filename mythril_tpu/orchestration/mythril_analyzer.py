@@ -14,6 +14,7 @@ from ..analysis.security import fire_lasers
 from ..analysis.symbolic import SymExecWrapper
 from ..analysis.traceexplore import get_serializable_statespace
 from ..smt.solver import SolverStatistics
+from ..support.devices import DeviceUnavailable
 from ..support.loader import DynLoader
 from ..support.source_support import Source
 from ..support.support_args import args
@@ -212,6 +213,8 @@ class MythrilAnalyzer:
             except KeyboardInterrupt:
                 log.critical("keyboard interrupt: flushing partial results")
                 break
+            except DeviceUnavailable:
+                raise  # a configuration error, not this contract's
             except Exception:
                 log.exception(
                     "exception during %s analysis", contract.name

@@ -23,8 +23,8 @@ execution. This module supplies the planning half of the fix:
   a thief to ask at a round boundary.
 * **pick_width warm start** — persisted fork peaks seed
   ``lane_engine.PATH_HISTORY`` so the first sweep of a known
-  wide-forking contract engages a wide engine (and the tunneled
-  break-even gate) without re-learning the fork scale.
+  wide-forking contract engages a wide engine without re-learning the
+  fork scale.
 """
 
 import json
@@ -353,8 +353,8 @@ def make_shards(paths: Sequence[str], num_processes: int,
 
 def warm_path_history(disassembly, name: str,
                       stats: Dict[str, dict]) -> None:
-    """Seed lane_engine.PATH_HISTORY (pick_width / device_break_even)
-    from a persisted fork peak, best-effort."""
+    """Seed lane_engine.PATH_HISTORY (pick_width) from a persisted
+    fork peak, best-effort."""
     entry = stats.get(name)
     peak = int((entry or {}).get("fork_peak", 0) or 0)
     if peak <= 0:

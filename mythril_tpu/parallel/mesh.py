@@ -24,22 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-try:  # jax >= 0.4.35 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: the experimental home
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# the stepper's while_loop has no replication rule on several jax
-# releases; the flag that disables the (purely diagnostic) replication
-# check is `check_rep` up to 0.4.x and `check_vma` on newer jax
-import inspect as _inspect
-
-_SM_KW = set(_inspect.signature(shard_map).parameters)
-_NO_REP_CHECK = (
-    {"check_rep": False} if "check_rep" in _SM_KW
-    else {"check_vma": False} if "check_vma" in _SM_KW else {}
-)
 
 from ..ops import stepper
 from ..ops.stepper import CompiledCode, LaneState, Status
@@ -99,7 +85,8 @@ def sharded_run(
         mesh=mesh,
         in_specs=(code_specs, state_specs),
         out_specs=state_specs,
-        **_NO_REP_CHECK,
+        # the stepper's while_loop has no replication rule
+        check_vma=False,
     )
     def _run(code_local, state_local):
         return stepper.run(code_local, state_local, max_steps)

@@ -204,6 +204,14 @@ class SolverStatistics(object, metaclass=Singleton):
         #                                already holding their prefix
         self.async_overlap_ms = 0.0   # discharge_async solver time
         #                               hidden behind caller work
+        # device errors recovered on the host (support/devices.
+        # note_device_error): each is also logged at warning level,
+        # and chip_smoke.py fails when any is above 0
+        self.device_warmup_errors = 0   # window-variant warm-ups
+        self.device_explore_errors = 0  # lane-engine sweeps
+        self.device_prefilter_errors = 0  # open-state prefilter
+        self.device_screen_errors = 0   # interval/propagation screens
+        self.device_shadow_errors = 0   # verdict shadow prepass
         # metrics-registry absorption (support/telemetry/metrics.py):
         # the registry snapshot carries this whole counter block under
         # the "solver" key, so structured exports (flight recorder,
@@ -311,6 +319,11 @@ class SolverStatistics(object, metaclass=Singleton):
             "codec_ref_hits": self.codec_ref_hits,
             "codec_fallback_whole": self.codec_fallback_whole,
             "codec_drop_whole": self.codec_drop_whole,
+            "device_warmup_errors": self.device_warmup_errors,
+            "device_explore_errors": self.device_explore_errors,
+            "device_prefilter_errors": self.device_prefilter_errors,
+            "device_screen_errors": self.device_screen_errors,
+            "device_shadow_errors": self.device_shadow_errors,
             # every screen-answered query is a solver round trip that
             # never happened (the acceptance metric bench.py reports)
             "queries_saved": (

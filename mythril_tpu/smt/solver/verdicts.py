@@ -384,10 +384,11 @@ class VerdictCache:
 
     def _device_ok(self, n: int) -> bool:
         try:
-            from ...models.pruner import _device_threshold
+            from ...models.pruner import DEVICE_BATCH_THRESHOLD
             from ...support.devices import effective_tpu_lanes
 
-            return bool(effective_tpu_lanes()) and n >= _device_threshold()
+            return (bool(effective_tpu_lanes())
+                    and n >= DEVICE_BATCH_THRESHOLD)
         except Exception:
             return False
 
@@ -429,7 +430,10 @@ class VerdictCache:
                      for (_i, ts, delta) in items],
                     model.bv, model.bools)
             except Exception as exc:  # a screen, never an error path
-                log.debug("device shadow prepass failed: %s", exc)
+                from ...support.devices import note_device_error
+
+                note_device_error("device_shadow_errors",
+                                  "verdict shadow prepass", exc)
                 continue
             for (i, ts, _delta), p, r in zip(items, proved, rejected):
                 if p:

@@ -114,6 +114,16 @@ GROUPS: Sequence[Tuple[str, str, Gate, Tuple[Tuple[str, str], ...]]] = (
         ("whole", "codec_fallback_whole"),
         ("dropped", "codec_drop_whole"),
     )),
+    ("Device errors recovered on the host", "support/devices.py",
+     ("device_warmup_errors", "device_explore_errors",
+      "device_prefilter_errors", "device_screen_errors",
+      "device_shadow_errors"), (
+        ("warmup", "device_warmup_errors"),
+        ("explore", "device_explore_errors"),
+        ("prefilter", "device_prefilter_errors"),
+        ("screen", "device_screen_errors"),
+        ("shadow", "device_shadow_errors"),
+    )),
     ("Warm store", "docs/warm_store.md",
      ("warm_hits", "warm_misses", "verdicts_warmed",
       "static_warmed", "route_first_try_wins"), (

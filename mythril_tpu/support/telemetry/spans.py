@@ -8,7 +8,7 @@ the off path is a single attribute check returning a shared no-op
 context manager, so instrumented seams cost nothing measurable and
 change no behavior. Counters/metrics (metrics.py) stay on regardless.
 
-Span taxonomy (the ``subsystem.operation`` names every seam uses) is
+Span naming (the ``subsystem.operation`` names every seam uses) is
 documented in docs/observability.md; the crash flight recorder
 (flightrec.py) dumps this module's ring buffer post-mortem.
 

@@ -82,3 +82,18 @@ def test_symbolic_concretization():
     got = cd.concrete(s.model())
     assert len(got) == 4
     assert got[0] == 0xAB
+
+
+@pytest.mark.parametrize("cls", [ConcreteCalldata, BasicConcreteCalldata])
+def test_word_at_symbolic_offset(cls):
+    """A symbolic CALLDATALOAD offset (a dynamic-array argument) reads
+    the 32 bytes from that offset: a 256-bit word, never an empty
+    concat."""
+    cd = cls(0, DATA)
+    off = symbol_factory.BitVecSym("cdl_off", 256)
+    word = cd.get_word_at(off)
+    assert word.size() == 256
+    s = Solver()
+    s.add(off == 1)
+    s.add(word != int.from_bytes(bytes(DATA[1:33]), "big"))
+    assert s.check() == unsat

@@ -269,7 +269,7 @@ class TestLaneExport:
         lane_engine.DEFAULT_WINDOW = 32
         try:
             lane_engine.warm_variant(64, len(code), {}, 32, 8192,
-                                     seed_bucket=16, block=True)
+                                     seed_bucket=16)
             baseline, _ = _analyze(code_hex, 1, tpu_lanes=64)
             base_issues = _issues(baseline)
 
@@ -377,7 +377,15 @@ class TestSigtermResume:
             env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
             text=True)
         assert proc.stdout.readline().strip() == "READY"
-        time.sleep(2.5)  # well inside the delayed round
+        # the first round's checkpoint marks the second round's start:
+        # the kill lands inside that delayed round however slowly the
+        # process started (a fixed sleep raced slow starts under load)
+        ckpt = Path(out_dir) / "run.ckpt"
+        deadline = time.monotonic() + 120
+        while (not ckpt.exists() and proc.poll() is None
+               and time.monotonic() < deadline):
+            time.sleep(0.05)
+        time.sleep(0.5)
         proc.send_signal(signal.SIGTERM)
         proc.communicate(timeout=120)
         assert proc.returncode != 0  # died of SIGTERM, not completion

@@ -350,8 +350,7 @@ class TestCompileReuseAccounting:
         was_on = trace.enabled()
         trace.set_enabled(True)
         try:
-            assert lane_engine.warm_variant(8, 64, {}, 32, 512,
-                                            block=True)
+            assert lane_engine.warm_variant(8, 64, {}, 32, 512)
 
             def compile_spans():
                 return sum(
@@ -360,13 +359,11 @@ class TestCompileReuseAccounting:
 
             spans_after_compile = compile_spans()
             # same-epoch hit: no reuse booked (one-shot behavior)
-            assert lane_engine.warm_variant(8, 64, {}, 32, 512,
-                                            block=True)
+            assert lane_engine.warm_variant(8, 64, {}, 32, 512)
             assert ss.compile_reuse_hits == base
             # next request epoch: the hit is cross-request amortization
             lane_engine.REQUEST_EPOCH[0] += 1
-            assert lane_engine.warm_variant(8, 64, {}, 32, 512,
-                                            block=True)
+            assert lane_engine.warm_variant(8, 64, {}, 32, 512)
             assert ss.compile_reuse_hits == base + 1
             # ... and no new compile span was recorded for the hit
             assert compile_spans() == spans_after_compile

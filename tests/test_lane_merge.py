@@ -272,7 +272,7 @@ class TestEndToEnd:
         lane_engine.DEFAULT_WINDOW = 32
         try:
             lane_engine.warm_variant(64, len(code), {}, 32, 8192,
-                                     seed_bucket=16, block=True)
+                                     seed_bucket=16)
             lane_engine.RUN_STATS_TOTAL = {}
             issues_off, _off = _analyze(code, False, 64, 1)
             parked_off = lane_engine.RUN_STATS_TOTAL.get("parked", 0)
@@ -371,7 +371,7 @@ class TestGasWidening:
         lane_engine.DEFAULT_WINDOW = 32
         try:
             lane_engine.warm_variant(64, len(code), {}, 32, 8192,
-                                     seed_bucket=16, block=True)
+                                     seed_bucket=16)
             ss = SolverStatistics()
             monkeypatch.setenv("MTPU_MERGE_GASWIDEN", "0")
             issues_nowiden, d_nowiden = _analyze(code, True, 64, 1)
@@ -405,7 +405,7 @@ class TestGasWidening:
         lane_engine.DEFAULT_WINDOW = 32
         try:
             lane_engine.warm_variant(64, len(code), {}, 32, 8192,
-                                     seed_bucket=16, block=True)
+                                     seed_bucket=16)
             monkeypatch.setenv("MTPU_MERGE_GASWIDEN", "0")
             issues_a, d_a = _analyze(code, True, 64, 1)
             monkeypatch.setenv("MTPU_MERGE_GASWIDEN", "1")

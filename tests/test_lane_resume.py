@@ -18,7 +18,7 @@ def _fresh_stats():
 def _warm(n_lanes, code):
     for bucket in (16, n_lanes):
         lane_engine.warm_variant(n_lanes, len(code), {}, lane_engine.DEFAULT_WINDOW, 8192,
-                                 seed_bucket=bucket, block=True)
+                                 seed_bucket=bucket)
 
 
 def test_sha3_word_hashes_defer_without_parking():

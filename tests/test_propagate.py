@@ -164,8 +164,6 @@ def test_hinted_solves_verdict_parity():
 
     old_lanes = args.tpu_lanes
     args.tpu_lanes = 8
-    pruner._device_failures = 0
-    pruner._device_skip = 0
     ss = SolverStatistics()
     kills0, hints0 = ss.propagate_kills, ss.hinted_solves
     try:
@@ -257,26 +255,20 @@ def test_seed_tables_bucket_to_pow2():
 
 
 def test_device_failed_fatal_classification():
-    """Satellite: MemoryError/KeyboardInterrupt are FATAL — they
-    re-raise instead of silently disabling the device screen; ordinary
-    exceptions keep the bounded backoff."""
+    """MemoryError/KeyboardInterrupt are FATAL — they re-raise instead
+    of being counted as a device error the screen recovers from;
+    ordinary exceptions are counted."""
     from mythril_tpu.models import pruner
 
-    pruner._device_failures = 0
-    pruner._device_skip = 0
-    try:
-        with pytest.raises(MemoryError):
-            pruner._device_failed(MemoryError("oom"))
-        with pytest.raises(KeyboardInterrupt):
-            pruner._device_failed(KeyboardInterrupt())
-        # fatal paths must NOT have consumed backoff budget
-        assert pruner._device_failures == 0
-        pruner._device_failed(RuntimeError("transient"))
-        assert pruner._device_failures == 1
-        assert pruner._device_skip > 0
-    finally:
-        pruner._device_failures = 0
-        pruner._device_skip = 0
+    ss = SolverStatistics()
+    n0 = ss.device_screen_errors
+    with pytest.raises(MemoryError):
+        pruner._device_failed(MemoryError("oom"))
+    with pytest.raises(KeyboardInterrupt):
+        pruner._device_failed(KeyboardInterrupt())
+    assert ss.device_screen_errors == n0
+    pruner._device_failed(RuntimeError("transient"))
+    assert ss.device_screen_errors == n0 + 1
 
 
 def test_prescreen_respects_gates():
@@ -299,8 +291,6 @@ def test_prescreen_respects_gates():
         propagate.FORCE = True
         from mythril_tpu.models import pruner
 
-        pruner._device_failures = 0
-        pruner._device_skip = 0
         kills = propagate.prescreen(sets, range(len(sets)))
         assert set(kills) == set(range(10))
     finally:

@@ -460,7 +460,7 @@ class TestEndToEndRetireSoundness:
         lane_engine.DEFAULT_WINDOW = 32
         try:
             lane_engine.warm_variant(64, len(code), {}, 32, 8192,
-                                     seed_bucket=16, block=True)
+                                     seed_bucket=16)
             issues_off, d_off = _analyze(code, False, 64, 1)
             issues_on, d_on = _analyze(code, True, 64, 1)
         finally:

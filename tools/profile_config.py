@@ -103,7 +103,7 @@ def main():
             for bucket in (16, width):
                 lane_engine.warm_variant(
                     width, 1024, {}, lane_engine.DEFAULT_WINDOW, 8192,
-                    seed_bucket=bucket, block=True)
+                    seed_bucket=bucket)
         lane_engine.RUN_STATS_TOTAL = {}
         pr = cProfile.Profile()
         print(f"=== REGION START {time.strftime('%H:%M:%S')} ===",
