@@ -267,15 +267,12 @@ def bench_symbolic(n_lanes=4096, trials=None):
             "windows": stats.get("windows"),
             "sha3_resumed_in_place": stats.get("resumed"),
             "model_repairs": dict(repair.STATS),
-            # drain-pipeline overlap (docs/drain_pipeline.md): idle =
-            # device drained while the host ran the serial drain; busy
-            # = host work hidden behind device execution; wait = host
-            # blocked on the fused window pull
+            # retired states materialized behind the next window
+            # (docs/drain_pipeline.md); device idleness is read from a
+            # profiler trace, not from host clocks
             "overlap": {
                 k: stats.get(k, 0)
-                for k in ("overlap_idle_ms", "overlap_busy_ms",
-                          "device_wait_ms", "overlap_mat",
-                          "overlap_mat_ms")
+                for k in ("overlap_mat", "overlap_mat_ms")
             },
         },
     }
@@ -2941,9 +2938,9 @@ def bench_smoke():
     run-wide verdict cache — NO full corpus sweep. Fourteen stages:
 
     1. a tiny symbolic explore (2^4 paths, 64 lanes) through the lane
-       engine with fork pruning engaged, so the window-pipeline overlap
-       counters (overlap_idle/busy, device_wait) and the overlapped
-       fork screen (fork_screened/fork_killed) exercise for real;
+       engine with fork pruning engaged, so the window pipeline and
+       the overlapped fork screen (fork_screened/fork_killed) exercise
+       for real;
     2. a batched `check_batch` discharge over fork-sibling constraint
        sets (shared prefixes, a contradiction, and its superset), so
        prefix-dedup and subset-kill provably count;
@@ -3108,9 +3105,6 @@ def bench_smoke():
         out["lane"] = {
             "wall_s": round(wall, 2), "paths": paths,
             "windows": eng.get("windows", 0),
-            "overlap_idle_ms": eng.get("overlap_idle_ms", 0),
-            "overlap_busy_ms": eng.get("overlap_busy_ms", 0),
-            "device_wait_ms": eng.get("device_wait_ms", 0),
             "overlap_solve_ms": eng.get("overlap_solve_ms", 0),
             "fork_screened": eng.get("fork_screened", 0),
             "fork_killed": eng.get("fork_killed", 0),

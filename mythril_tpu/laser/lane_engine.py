@@ -32,7 +32,6 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from copy import copy, deepcopy
 from typing import Dict, List, Optional, Tuple
 
@@ -206,28 +205,6 @@ class _LazyOState:
         if self._gs is None:
             self._gs = self._site.build_state()
         return getattr(self._gs, name)
-
-
-#: phase wall-clock accumulator (seconds), enabled by MYTHRIL_TPU_PROF=1.
-#: In profiling mode device calls are block_until_ready'd inside their
-#: phase so async dispatch cost lands on the phase that caused it.
-PROF: Dict[str, float] = {}
-PROF_ON = os.environ.get("MYTHRIL_TPU_PROF") == "1"
-
-
-@contextmanager
-def _prof(name: str, sync=None):
-    if not PROF_ON:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        if sync is not None:
-            jax.block_until_ready(sync() if callable(sync) else sync)
-        PROF[name] = PROF.get(name, 0.0) + time.perf_counter() - t0
-        PROF["n_" + name] = PROF.get("n_" + name, 0.0) + 1
 
 
 #: stats of the most recent completed explore() in this process — lets
@@ -1368,8 +1345,7 @@ def _compiled_code(code_bytes: bytes, fentries) -> "CompiledCode":
     if cc is None:
         loopsum_plane = (loop_summary.device_park_pcs(info)
                          if loopsum_heads else None)
-        with _prof("compile_code"), trace.span(
-                "xla.compile_code", code_len=len(code_bytes)):
+        with trace.span("xla.compile_code", code_len=len(code_bytes)):
             cc = compile_code(code_bytes, func_entries=key[1],
                               det_mask=det_mask,
                               loopsum_pcs=loopsum_plane)
@@ -1435,7 +1411,7 @@ def _compiled_packed(member_keys: tuple):
                 except Exception:
                     plane = None
             spec.append((code, fentries, plane))
-        with _prof("compile_code"), trace.span(
+        with trace.span(
                 "xla.compile_code",
                 code_len=sum(len(c) for c, _f, _h in key),
                 members=len(key)):
@@ -1765,12 +1741,9 @@ class LaneEngine:
             "seeded": 0, "reseeded": 0, "forks": 0, "records": 0,
             "parked": 0, "dead": 0, "device_steps": 0, "windows": 0,
             "resumed": 0, "overlap_mat": 0, "overlap_mat_ms": 0,
-            # window-pipeline overlap (docs/drain_pipeline.md):
-            # host-visible device idle (pull-complete -> next dispatch),
-            # host work overlapped with device execution, host blocked
-            # on the fused window pull, and the batched fork screen
-            "overlap_idle_ms": 0, "overlap_busy_ms": 0,
-            "device_wait_ms": 0, "overlap_solve_ms": 0,
+            # solver time of the overlapped fork screen
+            # (docs/drain_pipeline.md)
+            "overlap_solve_ms": 0,
             "fork_screened": 0, "fork_killed": 0,
             # window-boundary merge/subsume pass (docs/lane_merge.md)
             "lanes_merged": 0, "lanes_subsumed": 0, "merge_rounds": 0,
@@ -2024,12 +1997,11 @@ class LaneEngine:
         n = self.n_lanes
         n_env = symstep.N_ENV
         lanes, specs = [], []
-        with _prof("seed_pack"):
-            for lane, gs, member in entries:
-                ctx, spec = self._seed_spec(gs, calldata_cap, member)
-                ctxs[lane] = ctx
-                lanes.append(lane)
-                specs.append(spec)
+        for lane, gs, member in entries:
+            ctx, spec = self._seed_spec(gs, calldata_cap, member)
+            ctxs[lane] = ctx
+            lanes.append(lane)
+            specs.append(spec)
         n_depth = self.lane_kwargs.get("stack_depth", 64)
         mem_cap = self.lane_kwargs.get("memory_bytes", 4096)
         d_recs = self.lane_kwargs.get("dlog_records", 64)
@@ -2271,7 +2243,6 @@ class LaneEngine:
         promotions, and annotations inherit by construction (the
         interpreter's deepcopy-at-JUMPI semantics)."""
         d_recs = self.lane_kwargs.get("dlog_records", 64)
-        _t_drain_py = time.perf_counter() if PROF_ON else 0.0
         prov: Dict[Tuple[int, int], int] = {}
         dead: List[int] = []
         dead_set: set = set()
@@ -2416,10 +2387,6 @@ class LaneEngine:
         self.stats["records"] += len(recs)
         self.stats["forks"] += len(forks)
         self.stats["dead"] += len(dead)
-
-        if PROF_ON:
-            PROF["drain_py"] = PROF.get("drain_py", 0.0) \
-                + time.perf_counter() - _t_drain_py
         return prov, dead
 
     # -- materialization -----------------------------------------------------
@@ -3069,8 +3036,7 @@ class LaneEngine:
                 prov_pairs[j, 0] = lane * d_recs + slot
                 prov_pairs[j, 1] = oid
             try:
-                with _prof("merge_fp"), \
-                        trace.span("merge.fingerprint", groups=groups):
+                with trace.span("merge.fingerprint", groups=groups):
                     self._fp_boundary = np.asarray(
                         jax.device_get(_merge_fingerprint(
                             st, jnp.asarray(prov_pairs))))
@@ -3238,19 +3204,20 @@ class LaneEngine:
                      for i in range(0, len(lanes_sel), ch)]
         cap = min(ch, self.n_lanes) if ch > 0 else self.n_lanes
         chunks = []
-        for part in parts:
-            floors = retire_floors(part)
-            kp = _geo_bucket(len(part), cap, min(64, cap))
-            idx = np.full(kp, self.n_lanes, np.int32)
-            idx[: len(part)] = part
-            with _prof("retire_dispatch"):
+        with trace.span("lane.retire_dispatch", lanes=len(lanes_sel),
+                        chunks=len(parts)):
+            for part in parts:
+                floors = retire_floors(part)
+                kp = _geo_bucket(len(part), cap, min(64, cap))
+                idx = np.full(kp, self.n_lanes, np.int32)
+                idx[: len(part)] = part
                 st, rows = _retire_rows(st, jnp.asarray(idx), *floors)
                 for arr in rows:
                     try:
                         arr.copy_to_host_async()
                     except Exception:
                         break  # backend without async copies
-            chunks.append((part, rows, floors, time.perf_counter()))
+                chunks.append((part, rows, floors, time.perf_counter()))
         if ch > 0:
             self.stats["retire_chunks"] += len(parts)
             from ..smt.solver.solver_statistics import SolverStatistics
@@ -3336,8 +3303,7 @@ class LaneEngine:
             # as the escalation retire (docs/drain_pipeline.md): a
             # migration client asking for half a 64k wave must not
             # recreate the single-allocation shape chunking removed
-            with _prof("ckpt_export"), \
-                    trace.span("ckpt.export", lanes=len(sel)):
+            with trace.span("ckpt.export", lanes=len(sel)):
                 st, chunks = self._retire_chunked(st, sel,
                                                   retire_floors)
                 exported = []
@@ -3572,8 +3538,7 @@ class LaneEngine:
                     # on it — the measured hide of the deferred pull
                     self.stats["retire_overlap_ms"] += hidden_ms
                 _SS().bump(retire_overlap_ms=hidden_ms)
-                with _prof("retire_pull"), \
-                        trace.span("retire.pull", rows=len(items)):
+                with trace.span("retire.pull", rows=len(items)):
                     return _unpack_rows(jax.device_get(rows_ref),
                                         *floors)
 
@@ -3623,7 +3588,6 @@ class LaneEngine:
         pending_screen: List[tuple] = []
         screen_future = None
         screen_dead: List[int] = []
-        t_idle0 = None
         trace.begin("lane.explore", n_lanes=self.n_lanes,
                     entries=n_entries, code_len=code_len,
                     pack_members=len(members) if packed else 0)
@@ -3645,35 +3609,25 @@ class LaneEngine:
                     seed_bucket=full_bucket,
                 ):
                     seed_cap = full_bucket
-                entries = []
-                while queue and free and len(entries) < seed_cap:
-                    midx, gs = queue.popleft()
-                    if self.adapters and not all(
-                        ad.seed_ok(gs) for ad in self.adapters
-                    ):
-                        # host handles this entry
-                        deliver(mems[midx].owner if packed else None,
-                                gs)
-                        continue
-                    entries.append((free.pop(), gs, mems[midx]))
-                i32buf, u8buf, k, pv = self._pack_window(
-                    entries, ctxs, free, kill, calldata_cap,
-                    big=seed_cap > small, resumes=resumes)
+                with trace.span("lane.seed_pack"):
+                    entries = []
+                    while queue and free and len(entries) < seed_cap:
+                        midx, gs = queue.popleft()
+                        if self.adapters and not all(
+                            ad.seed_ok(gs) for ad in self.adapters
+                        ):
+                            # host handles this entry
+                            deliver(mems[midx].owner if packed
+                                    else None, gs)
+                            continue
+                        entries.append((free.pop(), gs, mems[midx]))
+                    i32buf, u8buf, k, pv = self._pack_window(
+                        entries, ctxs, free, kill, calldata_cap,
+                        big=seed_cap > small, resumes=resumes)
                 resumes = []
                 n_free_written = len(free)
-                _tw = time.perf_counter() if PROF_ON else 0.0
-                if t_idle0 is not None:
-                    # host-visible device idle: from the previous
-                    # window's pull completing (device drained) to this
-                    # dispatch being enqueued — the serial drain wall
-                    # the pipeline exists to shrink
-                    idle_ms = (time.perf_counter() - t_idle0) * 1000
-                    self.stats["overlap_idle_ms"] += int(idle_ms)
-                    _solver_stats.overlap_idle_ms += idle_ms
-                    t_idle0 = None
-                with _prof("window_exec", sync=lambda: st.pc), \
-                        trace.span("lane.window_dispatch",
-                                   seeds=k, window=self.window):
+                with trace.span("lane.window_dispatch",
+                                seeds=k, window=self.window):
                     st, visited, out = _window_exec(
                         st, cc, i32buf, u8buf, self.exec_table,
                         self.taint_table, self.window, k,
@@ -3697,7 +3651,6 @@ class LaneEngine:
                 # the dispatch above is asynchronous: while this window
                 # executes, pull+rebuild the LAST window's retired
                 # GlobalStates and discharge its fork-feasibility batch
-                t_busy0 = time.perf_counter()
                 ring.flush()
                 if screen_future is not None:
                     # started at the previous drain: with the pool
@@ -3708,13 +3661,6 @@ class LaneEngine:
                     screen_dead = self._collect_fork_screen(
                         screen_future)
                     screen_future = None
-                busy_ms = (time.perf_counter() - t_busy0) * 1000
-                self.stats["overlap_busy_ms"] += int(busy_ms)
-                _solver_stats.overlap_busy_ms += busy_ms
-                if PROF_ON:
-                    PROF.setdefault("windows", []).append(  # type: ignore
-                        (round(time.perf_counter() - _tw, 3), k,
-                         len(code_bytes), self.n_lanes))
                 self.stats["windows"] += 1
                 # device-dispatch accounting (docs/daemon.md §wave
                 # packing): window count feeds the bench "strictly
@@ -3734,16 +3680,10 @@ class LaneEngine:
                     if len(owners_live) > 1:
                         _solver_stats.bump(
                             dispatches_saved=len(owners_live) - 1)
-                t_wait0 = time.perf_counter()
-                with _prof("window_pull"), \
-                        trace.span("lane.window_pull"):
+                with trace.span("lane.window_pull"):
                     (misc, scal, utab, ftab, ridx, r_i32, r_u32,
                      r_u8, hidx, h_i32, h_u32, h_u8) = [
                         np.asarray(x) for x in jax.device_get(out)]
-                wait_ms = (time.perf_counter() - t_wait0) * 1000
-                self.stats["device_wait_ms"] += int(wait_ms)
-                _solver_stats.device_wait_ms += wait_ms
-                t_idle0 = time.perf_counter()
                 counts_h = {
                     "dlog_count": misc[:, 0], "status": misc[:, 1],
                     "steps": misc[:, 2], "sp": misc[:, 3],
@@ -3767,7 +3707,8 @@ class LaneEngine:
                     while urb_big < ucount and urb_big < cap:
                         urb_big *= 2
                     urb_big = min(urb_big, cap)
-                    with _prof("logs_escalate"):
+                    with trace.span("lane.escalate", log="records",
+                                    rows=urb_big):
                         utab, uc2 = jax.device_get(
                             _unique_table_big(st, urb_big))
                     utab = np.asarray(utab)
@@ -3776,28 +3717,32 @@ class LaneEngine:
                         raise RuntimeError(
                             f"{ucount} distinct records in one window "
                             f"exceed the escalation budget")
-                recs = []
-                for i in range(ucount):
-                    row = utab[i]
-                    recs.append((
-                        int(row[4]), int(row[0]), int(row[1]), int(row[2]),
-                        int(row[3]), int(row[5]),
-                        (int(row[6]), int(row[7]), int(row[8])),
-                        np.ascontiguousarray(row[9:]).view(np.uint32)
-                        .reshape(3, bv256.NLIMBS),
-                    ))
                 if nf > ftab.shape[0]:
-                    with _prof("flog_escalate"):
+                    with trace.span("lane.escalate", log="forks",
+                                    rows=nf):
                         ftab = np.asarray(jax.device_get(
                             _gather_full_flog(st)))
-                forks = []
-                for i in range(nf):
-                    r = ftab[i]
-                    forks.append((
-                        int(r[2]), int(r[0]), int(r[1]), int(r[3]),
-                        int(r[4]), int(np.uint32(r[5])),
-                        int(np.uint32(r[6])), int(r[7]), int(r[8]),
-                    ))
+                # decoding the window's record and fork tables is the
+                # first half of the drain; _drain_host below the second
+                with trace.span("lane.drain", records=ucount, forks=nf):
+                    recs = []
+                    for i in range(ucount):
+                        row = utab[i]
+                        recs.append((
+                            int(row[4]), int(row[0]), int(row[1]),
+                            int(row[2]), int(row[3]), int(row[5]),
+                            (int(row[6]), int(row[7]), int(row[8])),
+                            np.ascontiguousarray(row[9:]).view(np.uint32)
+                            .reshape(3, bv256.NLIMBS),
+                        ))
+                    forks = []
+                    for i in range(nf):
+                        r = ftab[i]
+                        forks.append((
+                            int(r[2]), int(r[0]), int(r[1]), int(r[3]),
+                            int(r[4]), int(np.uint32(r[5])),
+                            int(np.uint32(r[6])), int(r[7]), int(r[8]),
+                        ))
                 status = counts_h["status"].copy()
                 steps = counts_h["steps"]
                 # forked children consumed slots from the top (tail) of the
@@ -3861,7 +3806,8 @@ class LaneEngine:
                     )
 
                 def _materialize_rows(lanes_sel, rows_host):
-                    with _prof("materialize"):
+                    with trace.span("lane.materialize",
+                                    n=len(lanes_sel)):
                         for row, lane in enumerate(lanes_sel):
                             self.stats["device_steps"] += \
                                 int(steps[lane])
@@ -3879,7 +3825,10 @@ class LaneEngine:
                     st, rest_chunks = self._retire_chunked(
                         st, rest, _retire_floors)
 
-                self._prov, dead = self._drain_host(recs, forks, ctxs)
+                with trace.span("lane.drain", records=len(recs),
+                                forks=len(forks)):
+                    self._prov, dead = self._drain_host(recs, forks,
+                                                        ctxs)
                 dead_set = set(dead)
 
                 # merge-before-spill (docs/drain_pipeline.md): the
@@ -3891,8 +3840,10 @@ class LaneEngine:
                 # would have merged at the next dispatch
                 spill_dropped: set = set()
                 if fast or rest:
-                    spill_dropped = self._spill_merge(
-                        st, fast + rest, ctxs, dead_set, counts_h)
+                    with trace.span("lane.spill_merge",
+                                    lanes=len(fast) + len(rest)):
+                        spill_dropped = self._spill_merge(
+                            st, fast + rest, ctxs, dead_set, counts_h)
 
                 # in-place resume (needs self._prov): patches ride the
                 # next dispatch's seed buffer — zero extra round trips.
@@ -3905,7 +3856,7 @@ class LaneEngine:
                 if held:
                     pcs = counts_h["pc"]
                     rrows = _unpack_resume((h_i32, h_u32, h_u8))
-                    with _prof("resume_host"):
+                    with trace.span("lane.resume_host", held=len(held)):
                         for row_i, lane in enumerate(held):
                             patch = None
                             if lane not in dead_set:
@@ -3921,9 +3872,11 @@ class LaneEngine:
                                 declined.append(lane)
 
                 if fast:
-                    st_fast = _unpack_rows((r_i32, r_u32, r_u8),
-                                           *RETIRE_FLOORS)
-                    with _prof("materialize"):
+                    # decode the fast-retired rows and park them in the
+                    # ring (codec-encoded); they materialize at flush
+                    with trace.span("retire.park", n=len(fast)):
+                        st_fast = _unpack_rows((r_i32, r_u32, r_u8),
+                                               *RETIRE_FLOORS)
                         items = []
                         for row, lane in enumerate(fast):
                             self.stats["device_steps"] += int(steps[lane])
@@ -3961,7 +3914,7 @@ class LaneEngine:
                     st, dchunks = self._retire_chunked(
                         st, declined, _retire_floors)
                     for part, drows, dfloors, _t in dchunks:
-                        with _prof("retire_pull"):
+                        with trace.span("retire.pull", rows=len(part)):
                             d_host = _unpack_rows(
                                 jax.device_get(drows), *dfloors)
                         _materialize_rows(part, d_host)
@@ -3992,8 +3945,9 @@ class LaneEngine:
                 # mask ride the next dispatch's kill list with zero
                 # solver/materialize work. Runs BEFORE the merge pass,
                 # which then never pays fingerprint work for them.
-                self._static_retire(status, ctxs, dead_set, kill,
-                                    counts_h, resumes)
+                with trace.span("lane.static_retire"):
+                    self._static_retire(status, ctxs, dead_set, kill,
+                                        counts_h, resumes)
                 # window-boundary lane merge/subsume (MTPU_MERGE,
                 # docs/lane_merge.md): exact-frontier twins collapse
                 # under an OR'd constraint suffix, implied siblings
@@ -4001,8 +3955,9 @@ class LaneEngine:
                 # (same protocol as trivially-false lanes), BEFORE that
                 # window executes, so a merged-away lane never runs
                 # another step
-                self._window_merge(st, status, ctxs, dead_set, kill,
-                                   counts_h, resumes)
+                with trace.span("lane.window_merge"):
+                    self._window_merge(st, status, ctxs, dead_set, kill,
+                                       counts_h, resumes)
                 # mid-flight wave export (MTPU_CKPT,
                 # docs/checkpoint.md): a work-stealing client can take
                 # the tail of the live wave at this boundary — the
@@ -4121,7 +4076,7 @@ class LaneEngine:
         pool = _STATE_POOL.get(self._shape_key())
         if pool:
             return pool.pop()
-        with _prof("init_lanes"):
+        with trace.span("lane.init", n_lanes=self.n_lanes):
             st = symstep.init_sym_lanes(self.n_lanes,
                                         **self.lane_kwargs)
             if self._lane_sh is not None:

@@ -185,6 +185,9 @@ class LaserEVM:
             raise ValueError(
                 "Symbolic execution started with invalid parameters"
             )
+        # the whole symbolic execution of one contract (B/E: the body
+        # keeps its shape; an exception leaves the B unmatched)
+        trace.begin("svm.sym_exec")
 
         log.debug("Starting LASER execution")
         for hook in self._start_sym_exec_hooks:
@@ -229,6 +232,7 @@ class LaserEVM:
             )
         for hook in self._stop_sym_exec_hooks:
             hook()
+        trace.end("svm.sym_exec")
 
     def resume_exec(self, open_states, address, start_round: int,
                     inflight=None) -> None:
@@ -575,6 +579,9 @@ class LaserEVM:
         instruction it cannot model. The host loop below continues from
         those, so hooks/detectors/transaction semantics are unchanged
         for everything host-executed."""
+        # the sweep's host work before each explore (B/E: closed
+        # before every return and before each group's explore)
+        trace.begin("svm.sweep_prep")
         from .lane_engine import (
             LaneEngine,
             code_to_bytes,
@@ -596,6 +603,7 @@ class LaserEVM:
         if any(_essential(h) for h in self.instr_pre_hook.values()) \
                 or any(_essential(h)
                        for h in self.instr_post_hook.values()):
+            trace.end("svm.sweep_prep")
             return
         try:
             from ..analysis.module.lane_adapters import get_adapter
@@ -627,6 +635,7 @@ class LaserEVM:
             # a hook without an adapter pins every branch to the host:
             # the device cannot fork, so batching buys nothing
             log.info("lane engine idle: JUMPI hooked without an adapter")
+            trace.end("svm.sweep_prep")
             return
         from ..ops import symstep as _symstep
 
@@ -673,6 +682,7 @@ class LaserEVM:
         # per-state scans.
         verdict = {id(gs): _device_ok(gs) for gs in self.work_list}
         if sum(verdict.values()) < min_batch:
+            trace.end("svm.sweep_prep")
             return  # device round trips don't pay for a trickle
         eligible = self.strategy.drain_eligible(
             lambda gs: verdict[id(gs)])
@@ -750,8 +760,10 @@ class LaserEVM:
         except Exception as e:
             log.debug("static pass context unavailable: %s", e)
         static_final = bool(self._static_final_tx)
+        trace.end("svm.sweep_prep")
 
         for code, states in groups.items():
+            trace.begin("svm.sweep_prep", states=len(states))
             # width right-sizing: args.tpu_lanes is the CAP; the engine
             # runs at the smallest bucket that fits this batch with
             # fork headroom (narrow planes = cheap init, transfers and
@@ -785,6 +797,7 @@ class LaserEVM:
             if not warm_variant(width, len(code), {},
                                 DEFAULT_WINDOW, DEFAULT_STEP_BUDGET):
                 self.work_list.extend(states)
+                trace.end("svm.sweep_prep")
                 continue
             mesh = pick_mesh(width)
             key = (code, width,
@@ -832,11 +845,16 @@ class LaserEVM:
                 from .wave_pack import current_client
 
                 _pack_client = current_client()
-                if _pack_client is not None:
-                    parked = _pack_client.explore(self, engine, code,
-                                                  states)
-                else:
-                    parked = engine.explore(code, states)
+                trace.end("svm.sweep_prep")
+                # the call into the lane engine: its own spans name the
+                # window loop; this one, the set-up and teardown around
+                # it (memo resets, coverage pull, freeing the explore)
+                with trace.span("svm.sweep_explore", states=len(states)):
+                    if _pack_client is not None:
+                        parked = _pack_client.explore(self, engine, code,
+                                                      states)
+                    else:
+                        parked = engine.explore(code, states)
             except Exception as e:  # any failure falls back to host
                 from ..support.devices import note_device_error
 
@@ -858,6 +876,9 @@ class LaserEVM:
                 except Exception:
                     pass
                 continue
+            # the sweep's host work after the explore (B/E, closed at
+            # the end of the group)
+            trace.begin("svm.sweep_retire", parked=len(parked))
             if static_mask is not None:
                 # host-side twin of the window-boundary retire: parked
                 # states that are statically dead never re-enter the
@@ -920,6 +941,7 @@ class LaserEVM:
                 len(states), len(parked), run["forks"],
                 run["device_steps"], run["records"], run["windows"],
             )
+            trace.end("svm.sweep_retire")
 
     def exec(self, create=False, track_gas=False
              ) -> Optional[List[GlobalState]]:
@@ -941,8 +963,17 @@ class LaserEVM:
         bus = None if create or track_gas else getattr(
             args, "migration_bus", None)
         midround_tick = 0
+        # instructions this loop executed (SolverStatistics.host_steps,
+        # booked once at exit), and whether the svm.host_exec span is
+        # open: one span per stretch of the loop between lane sweeps,
+        # whose end carries the steps of its stretch
+        host_steps = stretch_from = 0
+        in_host = False
         try:
             for global_state in self.strategy:
+                if not in_host:
+                    trace.begin("svm.host_exec")
+                    in_host, stretch_from = True, host_steps
                 # live-dump visibility (support/checkpoint.py): the
                 # state being executed was already popped from the
                 # worklist — a SIGTERM snapshot taken mid-step must
@@ -959,6 +990,7 @@ class LaserEVM:
                     log.debug("Hit execution timeout, returning.")
                     return final_states + [global_state] \
                         if track_gas else None
+                host_steps += 1
                 try:
                     new_states, op_code = self.execute_state(global_state)
                 except NotImplementedError:
@@ -987,7 +1019,11 @@ class LaserEVM:
                     and len(self.work_list) >= 32
                 ):
                     iter_since_sweep = 0
+                    trace.end("svm.host_exec",
+                              steps=host_steps - stretch_from)
                     self._lane_engine_sweep(min_batch=32)
+                    trace.begin("svm.host_exec")
+                    stretch_from = host_steps
                 if new_states:
                     self.work_list += new_states
                 elif track_gas:
@@ -1042,6 +1078,14 @@ class LaserEVM:
                         if peak > seen:
                             self._record_fork_scale(code_obj, peak)
         finally:
+            if in_host:
+                trace.end("svm.host_exec", steps=host_steps - stretch_from)
+            if host_steps:
+                from ..smt.solver.solver_statistics import (
+                    SolverStatistics,
+                )
+
+                SolverStatistics().bump(host_steps=host_steps)
             # cross-state PotentialIssue wave: every end state's
             # candidates screen in ONE interval batch (device-sized
             # where per-state discharge saw only a handful), then the
@@ -1137,7 +1181,8 @@ class LaserEVM:
         self._pi_wave = []
         from ..analysis.potential_issues import discharge_wave
 
-        discharge_wave(states)
+        with trace.span("svm.pi_wave", states=len(states)):
+            discharge_wave(states)
 
     def execute_state(
         self, global_state: GlobalState

@@ -18,6 +18,7 @@ from ..support.devices import DeviceUnavailable
 from ..support.loader import DynLoader
 from ..support.source_support import Source
 from ..support.support_args import args
+from ..support.telemetry import trace
 
 log = logging.getLogger(__name__)
 
@@ -193,6 +194,9 @@ class MythrilAnalyzer:
         from ..support import warm_store
 
         for contract in self.contracts:
+            # the entry layer's span per contract: reset, wrapper set-up,
+            # symbolic execution (svm.sym_exec), detectors, issues
+            trace.begin("analysis.contract", contract=contract.name)
             try:
                 # fresh solver session + keccak axioms per contract:
                 # another contract's clauses and hash conditions only
@@ -229,6 +233,7 @@ class MythrilAnalyzer:
                     warm_store.end_analysis()
                 except Exception as e:
                     log.debug("warm-store save failed: %s", e)
+                trace.end("analysis.contract")
         stats = SolverStatistics()
         if getattr(stats, "enabled", False):
             log.info("solver statistics: %s", stats)

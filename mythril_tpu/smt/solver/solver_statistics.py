@@ -189,10 +189,9 @@ class SolverStatistics(object, metaclass=Singleton):
         #                               whole (corrupt/skew/missing
         #                               reference — never partially
         #                               adopted)
-        # window-pipeline overlap (laser/lane_engine.explore)
-        self.overlap_idle_ms = 0.0    # device idle while host drained
-        self.overlap_busy_ms = 0.0    # host work overlapped with device
-        self.device_wait_ms = 0.0     # host blocked on the window pull
+        # host interpreter (laser/svm.py LaserEVM.exec)
+        self.host_steps = 0           # instructions the host loop
+        #                               executed (execute_state calls)
         # persistent solver pool (smt/solver/pool.py — see
         # docs/solver_pool.md)
         self.pool_workers = 0         # configured worker count (gauge)
@@ -331,9 +330,7 @@ class SolverStatistics(object, metaclass=Singleton):
                 + self.quick_sat_hits + self.verdict_hits
                 + self.verdict_shadows + self.verdict_unsat_kills
             ),
-            "overlap_idle_ms": round(self.overlap_idle_ms, 1),
-            "overlap_busy_ms": round(self.overlap_busy_ms, 1),
-            "device_wait_ms": round(self.device_wait_ms, 1),
+            "host_steps": self.host_steps,
             # persistent solver pool (docs/solver_pool.md)
             "pool_workers": self.pool_workers,
             "queries_pooled": self.queries_pooled,

@@ -37,11 +37,8 @@ GROUPS: Sequence[Tuple[str, str, Gate, Tuple[Tuple[str, str], ...]]] = (
         ("bound_seeds", "verdict_bound_seeds"),
         ("queries_saved", "queries_saved"),
     )),
-    ("Drain overlap", "docs/drain_pipeline.md",
-     ("overlap_idle_ms", "overlap_busy_ms", "device_wait_ms"), (
-        ("idle_ms", "overlap_idle_ms"),
-        ("busy_ms", "overlap_busy_ms"),
-        ("device_wait_ms", "device_wait_ms"),
+    ("Host interpreter", "docs/observability.md", ("host_steps",), (
+        ("steps", "host_steps"),
     )),
     ("Propagation", "docs/propagation.md",
      ("propagate_kills", "facts_harvested", "hinted_solves"), (

@@ -16,10 +16,22 @@ All span timing uses ``time.monotonic()`` — wall clocks step under
 NTP and a stepped span would corrupt latency histograms the same way
 it corrupted ``steal_latency_s`` (see tools/lint_static.py rule
 ``wall-clock-in-monotonic-path``).
+
+With tracing on and a ``jax.profiler`` session recording, every
+duration span (``span``, ``begin``/``end``, ``call_jit``) also opens a
+profiler TraceMe of the same name on the thread that runs it, so the
+profiler's trace holds the program's spans on its own clock beside the
+device ops. JAX is looked up in ``sys.modules`` and never imported
+here: a run that has not imported it pays nothing. Tracing on also
+records each full (generation 2) garbage collection as a ``gc.collect``
+span: the pause lands wherever an allocation trips it, and would
+otherwise be charged to whatever span it interrupted.
 """
 
+import gc
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -69,6 +81,7 @@ def enabled() -> bool:
 def set_enabled(on: bool) -> None:
     """Runtime gate override (bench stages, tests, --trace-out)."""
     _STATE.on = bool(on)
+    _hook_gc()
 
 
 def configure(capacity: Optional[int] = None,
@@ -82,11 +95,12 @@ def configure(capacity: Optional[int] = None,
             _STATE.recorded = 0
             _STATE.dropped = 0
     if enable is not None:
-        _STATE.on = bool(enable)
+        set_enabled(enable)
 
 
 def clear() -> None:
     with _STATE.lock:
+        _GC_DONE.clear()
         _STATE.buf.clear()
         _STATE.recorded = 0
         _STATE.dropped = 0
@@ -94,6 +108,7 @@ def clear() -> None:
 
 def stats() -> dict:
     with _STATE.lock:
+        _flush_gc()
         return {"recorded": _STATE.recorded,
                 "dropped": _STATE.dropped,
                 "buffered": len(_STATE.buf),
@@ -101,25 +116,87 @@ def stats() -> dict:
                 "enabled": _STATE.on}
 
 
+def _open_traceme(name: str):
+    """An open jaxlib TraceMe (what ``jax.profiler.TraceAnnotation``
+    wraps) named ``name`` while a profiler session is recording in this
+    process, else None. Close it with ``__exit__(None, None, None)``."""
+    prof = sys.modules.get("jaxlib._profiler")
+    if prof is None or not prof.TraceMe.is_enabled():
+        return None
+    return prof.TraceMe(name)
+
+
+def _append(event: tuple, thread_name: str) -> None:
+    """Add one event to the ring (lock held)."""
+    s = _STATE
+    tid = event[4]
+    if tid not in s.tid_names:
+        s.tid_names[tid] = thread_name
+    if len(s.buf) >= s.cap:
+        s.dropped += 1  # ring semantics: newest wins
+    s.buf.append(event)
+    s.recorded += 1
+
+
 def _record(phase: str, name: str, t0: float, dur: float,
             attrs: Optional[dict]) -> None:
     th = threading.current_thread()
-    tid = th.ident or 0
-    s = _STATE
-    with s.lock:
-        if tid not in s.tid_names:
-            s.tid_names[tid] = th.name
-        if len(s.buf) >= s.cap:
-            s.dropped += 1  # ring semantics: newest wins
-        s.buf.append((phase, name, t0 - _EPOCH, dur, tid, attrs))
-        s.recorded += 1
+    with _STATE.lock:
+        _flush_gc()
+        _append((phase, name, t0 - _EPOCH, dur, th.ident or 0, attrs),
+                th.name)
+
+
+# -- garbage-collection pauses -------------------------------------------
+
+#: finished gc.collect spans, (event, thread name), handed to the ring at
+#: its next write or read: a collection can start while its thread holds
+#: the ring's lock, so the gc callback never takes it (deque appends and
+#: pops are atomic)
+_GC_DONE: deque = deque()
+#: per thread: the full collection in progress, (TraceMe or None, start)
+_gc_open = threading.local()
+
+
+def _flush_gc() -> None:
+    while _GC_DONE:
+        _append(*_GC_DONE.popleft())
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    if info.get("generation") != 2:
+        return
+    if phase == "start":
+        _gc_open.span = (_open_traceme("gc.collect"), time.monotonic())
+        return
+    opened = getattr(_gc_open, "span", None)
+    if opened is None:
+        return
+    _gc_open.span = None
+    tm, t0 = opened
+    t1 = time.monotonic()
+    if tm is not None:
+        tm.__exit__(None, None, None)
+    th = threading.current_thread()
+    _GC_DONE.append((("X", "gc.collect", t0 - _EPOCH, t1 - t0,
+                      th.ident or 0,
+                      {"collected": info.get("collected", 0)}), th.name))
+
+
+def _hook_gc() -> None:
+    """gc.callbacks holds _gc_span exactly while tracing is on."""
+    hooked = _gc_span in gc.callbacks
+    if _STATE.on and not hooked:
+        gc.callbacks.append(_gc_span)
+    elif not _STATE.on and hooked:
+        gc.callbacks.remove(_gc_span)
 
 
 class _Span:
     """One traced region. ``set(**attrs)`` adds attributes after
     entry (e.g. a verdict known only at exit)."""
 
-    __slots__ = ("name", "attrs", "t0")
+    __slots__ = ("name", "attrs", "t0", "tm")
 
     def __init__(self, name: str, attrs: Optional[dict]):
         self.name = name
@@ -132,14 +209,17 @@ class _Span:
             self.attrs.update(attrs)
 
     def __enter__(self) -> "_Span":
+        self.tm = _open_traceme(self.name)
         self.t0 = time.monotonic()
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
+        t1 = time.monotonic()
+        if self.tm is not None:
+            self.tm.__exit__(None, None, None)
         if et is not None:
             self.set(error=et.__name__)
-        _record("X", self.name, self.t0,
-                time.monotonic() - self.t0, self.attrs)
+        _record("X", self.name, self.t0, t1 - self.t0, self.attrs)
         return False
 
 
@@ -171,10 +251,15 @@ def span(name: str, **attrs):
 
 
 def event(name: str, **attrs) -> None:
-    """Instant (zero-duration) event — offer/claim/replay marks."""
+    """Instant (zero-duration) event — offer/claim/replay marks. Ring
+    only: the profiler trace gets no instant events."""
     if not _STATE.on:
         return
     _record("i", name, time.monotonic(), 0.0, attrs or None)
+
+
+#: per thread: the stack of open begin() regions, [(name, TraceMe or None)]
+_open = threading.local()
 
 
 def begin(name: str, **attrs) -> None:
@@ -184,13 +269,25 @@ def begin(name: str, **attrs) -> None:
     closes it at trace end)."""
     if not _STATE.on:
         return
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    stack.append((name, _open_traceme(name)))
     _record("B", name, time.monotonic(), 0.0, attrs or None)
 
 
 def end(name: str, **attrs) -> None:
+    """Close the current thread's innermost open ``begin(name)``."""
     if not _STATE.on:
         return
     _record("E", name, time.monotonic(), 0.0, attrs or None)
+    stack = getattr(_open, "stack", None) or []
+    for i in range(len(stack) - 1, -1, -1):
+        if stack[i][0] == name:
+            tm = stack.pop(i)[1]
+            if tm is not None:
+                tm.__exit__(None, None, None)
+            return
 
 
 def call_jit(name: str, jfn, *args, **kwargs):
@@ -200,7 +297,9 @@ def call_jit(name: str, jfn, *args, **kwargs):
     self-evident in any trace), otherwise a plain execute span named
     ``name``. Warm execute spans measure DISPATCH time (jax dispatch
     is async); compile happens synchronously inside the call so
-    compile spans are true walls. Tracing off: a direct call."""
+    compile spans are true walls. The profiler's event is named ``name``
+    either way (it opens before the call can tell) and carries
+    ``xla_compile=1`` on a compile. Tracing off: a direct call."""
     if not _STATE.on:
         return jfn(*args, **kwargs)
     size_fn = getattr(jfn, "_cache_size", None)
@@ -210,6 +309,7 @@ def call_jit(name: str, jfn, *args, **kwargs):
             before = size_fn()
         except Exception:
             before = None
+    tm = _open_traceme(name)
     t0 = time.monotonic()
     out = jfn(*args, **kwargs)
     dur = time.monotonic() - t0
@@ -219,6 +319,10 @@ def call_jit(name: str, jfn, *args, **kwargs):
             compiled = size_fn() > before
         except Exception:
             pass
+    if tm is not None:
+        if compiled:
+            tm.set_metadata(xla_compile=1)
+        tm.__exit__(None, None, None)
     if compiled:
         _record("X", "xla.compile", t0, dur, {"kernel": name})
         try:
@@ -262,6 +366,7 @@ def current_query_context() -> dict:
 def snapshot_events() -> List[tuple]:
     """A consistent copy of the ring buffer (oldest first)."""
     with _STATE.lock:
+        _flush_gc()
         return list(_STATE.buf)
 
 
@@ -270,6 +375,7 @@ def chrome_trace_dict(rank: int = 0) -> dict:
     the ring buffer — ``pid`` is the corpus rank so multi-rank traces
     can be concatenated by merging traceEvents lists."""
     with _STATE.lock:
+        _flush_gc()
         events = list(_STATE.buf)
         names = dict(_STATE.tid_names)
     te = []
@@ -309,6 +415,7 @@ def export_jsonl(path, rank: int = 0) -> None:
     per line; grep/jq-friendly twin of the Chrome export)."""
     try:
         with _STATE.lock:
+            _flush_gc()
             events = list(_STATE.buf)
             names = dict(_STATE.tid_names)
         tmp = str(path) + ".tmp"
@@ -324,3 +431,6 @@ def export_jsonl(path, rank: int = 0) -> None:
         os.replace(tmp, str(path))
     except Exception:
         pass
+
+
+_hook_gc()

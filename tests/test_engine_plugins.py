@@ -100,11 +100,14 @@ def test_call_depth_limiter_cuts_recursion():
 
 def _run_symbolic_lane(code: bytes, stop_hook=None, lanes=64):
     """_run_symbolic with the lane sweep engaged (CPU backend:
-    break-even 1, so the wave dispatches)."""
+    break-even 1, so the wave dispatches). The execution budget also
+    pays the first sweep's XLA:CPU compile, which a loaded machine
+    stretches past a minute: a spent budget ends the analysis before
+    any path reaches STOP."""
     from mythril_tpu.laser import lane_engine
     from mythril_tpu.support.support_args import args
 
-    laser = LaserEVM(requires_statespace=False, execution_timeout=60,
+    laser = LaserEVM(requires_statespace=False, execution_timeout=600,
                      transaction_count=1)
     if stop_hook is not None:
         laser.pre_hook("STOP")(stop_hook)
@@ -114,7 +117,7 @@ def _run_symbolic_lane(code: bytes, stop_hook=None, lanes=64):
     account.code = Disassembly(code.hex())
     laser.open_states = [world_state]
     laser.time = datetime.now()
-    time_handler.start_execution(60)
+    time_handler.start_execution(600)
     old_lanes = args.tpu_lanes
     args.tpu_lanes = lanes
     stats0 = dict(lane_engine.RUN_STATS_TOTAL)

@@ -142,7 +142,14 @@ def test_bounded_loops_cuts_concrete_loop():
         )
         return counter["n"]
 
-    bounded = run(True)
-    unbounded = run(False)
+    from mythril_tpu.support.support_args import args
+
+    old_lanes = args.tpu_lanes
+    args.tpu_lanes = 0  # host-only: the lane engine must not engage
+    try:
+        bounded = run(True)
+        unbounded = run(False)
+    finally:
+        args.tpu_lanes = old_lanes
     assert unbounded > 500  # the full 100-iteration loop runs
     assert bounded < unbounded / 5  # the bound cuts it off early

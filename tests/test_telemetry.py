@@ -6,6 +6,7 @@ log, crash flight recorder (in-process dump + induced fatal and
 SIGTERM in subprocesses), and the monotonic staleness clock the
 migration bus dead-thief timeout now runs on."""
 
+import gc
 import json
 import os
 import signal
@@ -27,8 +28,11 @@ REPO = Path(__file__).resolve().parent.parent
 
 @pytest.fixture
 def traced():
-    """Enabled tracing with a fresh buffer; restores prior state."""
+    """Enabled tracing with a fresh buffer; restores prior state. A full
+    collection first: tracing records each one as a gc.collect span,
+    and none may land among a test's exact event counts."""
     was = trace.enabled()
+    gc.collect()
     trace.clear()
     trace.set_enabled(True)
     yield trace
@@ -153,6 +157,131 @@ def test_query_context_nesting():
         assert trace.current_query_context() == {
             "tier": "outer", "tactic": "a"}
     assert trace.current_query_context() == {}
+
+
+# -- spans on the profiler's clock --------------------------------------
+
+
+def _profiled(tmp_path, body):
+    """Run body() under jax.profiler.trace; the trace's host events as
+    {name: [duration in seconds]}."""
+    import jax
+
+    with jax.profiler.trace(str(tmp_path)):
+        body()
+    found = sorted(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(str(found[-1]))
+    out = {}
+    for plane in pd.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(e.duration_ns / 1e9)
+    return out
+
+
+def _ring_durations() -> dict:
+    out, opened = {}, {}
+    for phase, name, t0, dur, tid, _attrs in trace.snapshot_events():
+        if phase == "X":
+            out.setdefault(name, []).append(dur)
+        elif phase == "B":
+            opened[(tid, name)] = t0
+        elif phase == "E":
+            out.setdefault(name, []).append(t0 - opened.pop((tid, name)))
+    return out
+
+
+def test_spans_are_native_profiler_events(tmp_path, traced):
+    """With tracing on, a span, a begin/end pair and a call_jit call
+    are host events of the same names in the profiler's trace, each as
+    long as the ring's span; instant events stay in the ring."""
+
+    def body():
+        with trace.span("prof.span", k=1):
+            time.sleep(0.02)
+        trace.begin("prof.region")
+        with trace.span("prof.inner"):
+            time.sleep(0.01)
+        trace.end("prof.region")
+        trace.event("prof.instant")
+        trace.call_jit("prof.kernel", lambda v: v + 1, 1)
+
+    native = _profiled(tmp_path, body)
+    ring = _ring_durations()
+    for name in ("prof.span", "prof.region", "prof.inner", "prof.kernel"):
+        assert len(native.get(name, [])) == 1, name
+        assert native[name][0] == pytest.approx(ring[name][0], abs=1e-3)
+    assert native["prof.region"][0] >= native["prof.inner"][0] > 0.009
+    assert "prof.instant" not in native
+    assert [e[1] for e in trace.snapshot_events()].count("prof.instant") == 1
+
+
+def test_begin_end_pair_per_thread(tmp_path, traced):
+    """begin/end pairs nest per thread: a region another thread opens
+    under the same name closes on its own thread."""
+
+    def other():
+        trace.begin("pair.region")
+        time.sleep(0.03)
+        trace.end("pair.region")
+
+    def body():
+        t = threading.Thread(target=other)
+        trace.begin("pair.region")
+        t.start()
+        time.sleep(0.005)
+        trace.end("pair.region")
+        t.join(timeout=10)
+        assert not t.is_alive()
+
+    native = _profiled(tmp_path, body)
+    assert sorted(native["pair.region"]) == pytest.approx(
+        sorted(_ring_durations()["pair.region"]), abs=1e-3)
+    assert max(native["pair.region"]) > 0.025 > min(native["pair.region"])
+
+
+def test_spans_off_reach_neither_ring_nor_trace(tmp_path):
+    was = trace.enabled()
+    trace.set_enabled(False)
+    trace.clear()
+    try:
+        def body():
+            with trace.span("off.span"):
+                pass
+            trace.begin("off.region")
+            trace.end("off.region")
+            trace.call_jit("off.kernel", lambda v: v, 1)
+
+        native = _profiled(tmp_path, body)
+        assert not [n for n in native if n.startswith("off.")]
+        assert trace.snapshot_events() == []
+    finally:
+        trace.set_enabled(was)
+
+
+def test_full_collections_are_spans(traced):
+    """With tracing on, a full garbage collection is a gc.collect span
+    in the ring; off, the gc callback is gone."""
+    assert trace._gc_span in gc.callbacks
+    gc.collect()
+    spans = [e for e in trace.snapshot_events() if e[1] == "gc.collect"]
+    assert len(spans) == 1
+    phase, _name, _t0, dur, _tid, attrs = spans[0]
+    assert phase == "X" and dur >= 0 and "collected" in attrs
+    gc.collect(0)  # a young collection is no span
+    assert [e[1] for e in trace.snapshot_events()].count("gc.collect") == 1
+    trace.set_enabled(False)
+    assert trace._gc_span not in gc.callbacks
+    gc.collect()
+    assert [e[1] for e in trace.snapshot_events()].count("gc.collect") == 1
+
+
+def test_lane_engine_has_no_phase_clocks():
+    from mythril_tpu.laser import lane_engine
+
+    assert not hasattr(lane_engine, "PROF")
+    assert not hasattr(lane_engine, "PROF_ON")
 
 
 # -- Chrome trace / JSONL export ----------------------------------------

@@ -123,11 +123,6 @@ def main():
                       "wall_s": round(wall, 2), **r}), flush=True)
     print("RUN_STATS_TOTAL:", json.dumps(lane_engine.RUN_STATS_TOTAL),
           flush=True)
-    from mythril_tpu.laser import lane_engine as le
-
-    if le.PROF_ON:
-        print("LANE PROF:", json.dumps(
-            {k: v for k, v in le.PROF.items()}, default=str), flush=True)
     if prof:
         s = io.StringIO()
         ps = pstats.Stats(pr, stream=s).sort_stats("cumulative")
