@@ -21,6 +21,7 @@ from datetime import datetime, timedelta
 from typing import Callable, Dict, List, Optional, Tuple
 
 from ..smt import symbol_factory
+from ..support import gc_schedule
 from ..support.opcodes import OPCODES
 from ..support.support_args import args
 from ..support.telemetry import trace
@@ -185,6 +186,8 @@ class LaserEVM:
             raise ValueError(
                 "Symbolic execution started with invalid parameters"
             )
+        # freeze the warmed heap once the process stops compiling
+        gc_schedule.before_analysis()
         # the whole symbolic execution of one contract (B/E: the body
         # keeps its shape; an exception leaves the B unmatched)
         trace.begin("svm.sym_exec")

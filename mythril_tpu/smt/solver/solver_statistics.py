@@ -4,6 +4,7 @@ around a timing context manager; the decorator form the reference uses
 is kept as a thin shim over it)."""
 
 import functools
+import gc
 import threading
 from contextlib import contextmanager
 
@@ -192,6 +193,13 @@ class SolverStatistics(object, metaclass=Singleton):
         # host interpreter (laser/svm.py LaserEVM.exec)
         self.host_steps = 0           # instructions the host loop
         #                               executed (execute_state calls)
+        # garbage-collection schedule (support/gc_schedule.py)
+        self.gc_freezes = 0           # heap freezes at an analysis's
+        #                               start (after a compile)
+        self.gc_frozen = 0            # objects frozen (gauge)
+        self.gc_full = 0              # full collections the process
+        #                               ran, counted by _count_full
+        gc.callbacks.append(self._count_full)
         # persistent solver pool (smt/solver/pool.py — see
         # docs/solver_pool.md)
         self.pool_workers = 0         # configured worker count (gauge)
@@ -230,6 +238,14 @@ class SolverStatistics(object, metaclass=Singleton):
         d["query_count"] = self.query_count
         d["solver_time_s"] = round(self.solver_time, 3)
         return d
+
+    def _count_full(self, phase: str, info: dict) -> None:
+        """gc callback, always installed: count each full collection.
+        Collections never overlap, so the plain increment is exact; it
+        takes no lock, since a collection can start while its thread
+        holds the counters' lock."""
+        if phase == "stop" and info.get("generation") == 2:
+            self.gc_full += 1
 
     def bump(self, **deltas) -> None:
         """Atomically add deltas to counters (the only update path
@@ -331,6 +347,9 @@ class SolverStatistics(object, metaclass=Singleton):
                 + self.verdict_shadows + self.verdict_unsat_kills
             ),
             "host_steps": self.host_steps,
+            "gc_freezes": self.gc_freezes,
+            "gc_frozen": self.gc_frozen,
+            "gc_full": self.gc_full,
             # persistent solver pool (docs/solver_pool.md)
             "pool_workers": self.pool_workers,
             "queries_pooled": self.queries_pooled,

@@ -37,8 +37,12 @@ GROUPS: Sequence[Tuple[str, str, Gate, Tuple[Tuple[str, str], ...]]] = (
         ("bound_seeds", "verdict_bound_seeds"),
         ("queries_saved", "queries_saved"),
     )),
-    ("Host interpreter", "docs/observability.md", ("host_steps",), (
+    ("Host interpreter", "docs/observability.md",
+     ("host_steps", "gc_freezes", "gc_full"), (
         ("steps", "host_steps"),
+        ("gc_freezes", "gc_freezes"),
+        ("gc_frozen", "gc_frozen"),
+        ("gc_full", "gc_full"),
     )),
     ("Propagation", "docs/propagation.md",
      ("propagate_kills", "facts_harvested", "hinted_solves"), (
