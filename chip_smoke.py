@@ -310,8 +310,7 @@ def phase_four_chips() -> dict:
         sharded[0]._release_state(st)
     _check(LANES_AXIS in spec_axes and len(plane_devices) == 4,
            f"lane planes not sharded over 4 devices: {sh}")
-    # device 0 also holds what is not sharded; every device must hold
-    # at least its quarter of the lane planes
+    # every device must hold at least its quarter of the lane planes
     mesh_peaks = [_peak_bytes(d) for d in devices]
     _check(min(mesh_peaks) >= shard_bytes,
            f"a device holds less than its lane-plane shard of "

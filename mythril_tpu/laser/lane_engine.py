@@ -1438,41 +1438,48 @@ _WARM: Dict[tuple, str] = {}  # variant key -> "pending" | "ready"
 _WARM_LOCK = None
 
 
+def _mesh_key(mesh) -> Optional[tuple]:
+    return None if mesh is None \
+        else tuple(d.id for d in mesh.devices.flat)
+
+
 def _variant_key(n_lanes: int, code_len: int, lane_kwargs: dict,
-                 window: int, seed_bucket: int) -> tuple:
+                 window: int, seed_bucket: int, mesh=None) -> tuple:
     from ..ops.stepper import _code_bucket
 
     return (n_lanes, _code_bucket(code_len),
-            tuple(sorted(lane_kwargs.items())), window, seed_bucket)
+            tuple(sorted(lane_kwargs.items())), window, seed_bucket,
+            _mesh_key(mesh))
 
 
 def _warm_one(n_lanes: int, code_len: int, lane_kwargs: dict,
               window: int, step_budget: int,
-              seed_bucket: int = 16) -> None:
+              seed_bucket: int = 16, mesh=None) -> None:
     """Compile one window-dispatch variant by running an all-dead
-    window of the exact production shapes."""
+    window of the exact production shapes, on the sweep's mesh."""
     with trace.span("xla.compile_variant", n_lanes=n_lanes,
                     code_len=code_len, window=window,
                     seed_bucket=seed_bucket):
         _warm_one_inner(n_lanes, code_len, lane_kwargs, window,
-                        step_budget, seed_bucket)
+                        step_budget, seed_bucket, mesh)
 
 
 def _warm_one_inner(n_lanes: int, code_len: int, lane_kwargs: dict,
                     window: int, step_budget: int,
-                    seed_bucket: int = 16) -> None:
+                    seed_bucket: int = 16, mesh=None) -> None:
     from ..ops.stepper import _code_bucket
 
     eng = LaneEngine(n_lanes=n_lanes, window=window,
-                     step_budget=step_budget, **lane_kwargs)
+                     step_budget=step_budget, mesh=mesh, **lane_kwargs)
     st = eng._acquire_state()
     # dummy code at the bucket length: shared across warms of the bucket
-    cc = _compiled_code(b"\x00" * _code_bucket(max(code_len, 1)), ())
+    code = b"\x00" * _code_bucket(max(code_len, 1))
+    cc = eng._placed_code(code, _compiled_code(code, ()))
     big = seed_bucket > min(16, n_lanes)
     i32buf, u8buf, k, pv = eng._pack_window(
         [], [None] * n_lanes, list(range(n_lanes)), [],
         int(st.calldata.shape[1]), big=big)
-    visited = jnp.zeros(cc.packed.shape[0], bool)
+    visited = eng._new_visited(cc)
     st, visited, out = _window_exec(
         st, cc, i32buf, u8buf, eng.exec_table, eng.taint_table,
         window, k, step_budget, pv, visited, eng._resume_flag)
@@ -1482,19 +1489,19 @@ def _warm_one_inner(n_lanes: int, code_len: int, lane_kwargs: dict,
 
 def warm_variant(n_lanes: int, code_len: int, lane_kwargs: dict,
                  window: int, step_budget: int,
-                 seed_bucket: int = 16) -> bool:
-    """Compile the (shape-)variant of the fused window dispatch, once
-    per process. True when it is compiled; False while another thread
-    is compiling it. Thread-safe; a failed warm-up is counted
-    (SolverStatistics.device_warmup_errors) and the sweep then meets
-    the same error itself."""
+                 seed_bucket: int = 16, mesh=None) -> bool:
+    """Compile the (shape-)variant of the fused window dispatch on
+    `mesh` (None: one device), once per process. True when it is
+    compiled; False while another thread is compiling it. Thread-safe;
+    a failed warm-up is counted (SolverStatistics.device_warmup_errors)
+    and the sweep then meets the same error itself."""
     global _WARM_LOCK
     import threading
 
     if _WARM_LOCK is None:
         _WARM_LOCK = threading.Lock()
     key = _variant_key(n_lanes, code_len, lane_kwargs, window,
-                       seed_bucket)
+                       seed_bucket, mesh)
     with _WARM_LOCK:
         state = _WARM.get(key)
         if state == "ready":
@@ -1506,7 +1513,7 @@ def warm_variant(n_lanes: int, code_len: int, lane_kwargs: dict,
         _WARM_EPOCH[key] = REQUEST_EPOCH[0]
     try:
         _warm_one(n_lanes, code_len, lane_kwargs, window,
-                  step_budget, seed_bucket)
+                  step_budget, seed_bucket, mesh)
     except Exception as e:
         from ..support.devices import note_device_error
 
@@ -1660,21 +1667,21 @@ class LaneEngine:
         #: rebuild the STOP transaction-end path never reads
         self.slim_stop = slim_stop
         # multi-device SPMD: when a jax.sharding.Mesh is supplied, the
-        # lane planes live sharded over its `lanes` axis and every
-        # fused dispatch runs SPMD under GSPMD partitioning — the SAME
-        # jitted programs, with XLA inserting the (rare) cross-device
-        # collectives the cumsum/scatter phases need. The host bridge
-        # (seed/drain/materialize) is unchanged: device_get gathers.
+        # lane planes are built sharded over its `lanes` axis
+        # (parallel/mesh.init_planes) and every fused dispatch runs
+        # SPMD under GSPMD partitioning — the SAME jitted programs,
+        # with XLA inserting the cross-device collectives the
+        # cumsum/scatter phases need. Host buffers go in replicated
+        # (_to_device); each pull gathers from every chip (_pull).
         self.mesh = mesh
-        self._lane_sh = self._rep_sh = None
+        self._rep_sh = None
         if mesh is not None:
-            from ..parallel.mesh import lane_sharding, replicated
+            from ..parallel.mesh import replicated
 
             if n_lanes % mesh.devices.size:
                 raise ValueError(
                     f"{n_lanes} lanes not divisible by "
                     f"{mesh.devices.size} mesh devices")
-            self._lane_sh = lane_sharding(mesh)
             self._rep_sh = replicated(mesh)
         #: per-code replicated compiled-code tensors (engines persist
         #: across explores; re-broadcasting cc each sweep is waste)
@@ -1801,6 +1808,55 @@ class LaneEngine:
         self._ring = None
         #: materialize() bumps stats off-thread under MTPU_MAT_WORKERS>1
         self._stats_lock = threading.Lock()
+
+    # -- placement on the mesh ---------------------------------------------
+
+    def _to_device(self, x):
+        """A host array for a dispatch: replicated over the mesh, so
+        every meshed program meets one fixed placement and none is
+        compiled twice; on one device, the default device as before."""
+        if self._rep_sh is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, self._rep_sh)
+
+    def _pull(self, x):
+        """jax.device_get of device arrays. On a mesh it gathers from
+        every chip: a mesh.pull span with the bytes it brings to the
+        host, which SolverStatistics.mesh_pull_bytes also counts."""
+        if self.mesh is None:
+            return jax.device_get(x)
+        nbytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(x))
+        with trace.span("mesh.pull", bytes=nbytes):
+            out = jax.device_get(x)
+        from ..smt.solver.solver_statistics import SolverStatistics
+
+        SolverStatistics().bump(mesh_pull_bytes=nbytes)
+        return out
+
+    def _placed_code(self, code_bytes: bytes, cc):
+        """cc for this engine's dispatches. On a mesh, the code tensors
+        and the op tables are replicated over every chip, memoized per
+        code: engines persist across explores and must not re-broadcast
+        every sweep."""
+        if self._rep_sh is None:
+            return cc
+        cc_r = self._cc_rep.get(code_bytes)
+        if cc_r is None:
+            cc_r = jax.tree_util.tree_map(
+                lambda x: jax.device_put(x, self._rep_sh), cc)
+            self._cc_rep[code_bytes] = cc_r
+            self.exec_table, self.taint_table, self._resume_flag = \
+                jax.device_put((self.exec_table, self.taint_table,
+                                self._resume_flag), self._rep_sh)
+        return cc_r
+
+    def _new_visited(self, cc):
+        """A cleared coverage bitmap, replicated over the mesh as the
+        window program returns it."""
+        visited = jnp.zeros(cc.packed.shape[0], bool)
+        if self._rep_sh is None:
+            return visited
+        return jax.device_put(visited, self._rep_sh)
 
     def _full_bucket(self) -> int:
         """Full-width seed bucket for backlog drains, kept strictly
@@ -2085,7 +2141,7 @@ class LaneEngine:
         self.stats["seeded"] += len(entries)
         # mid-path re-entries (the spill/refill path) vs fresh tx seeds
         self.stats["reseeded"] += sum(1 for s in specs if s["pc"])
-        return (jnp.asarray(i32buf), jnp.asarray(u8buf), k, pv)
+        return (self._to_device(i32buf), self._to_device(u8buf), k, pv)
 
     # -- drain ---------------------------------------------------------------
 
@@ -3038,8 +3094,8 @@ class LaneEngine:
             try:
                 with trace.span("merge.fingerprint", groups=groups):
                     self._fp_boundary = np.asarray(
-                        jax.device_get(_merge_fingerprint(
-                            st, jnp.asarray(prov_pairs))))
+                        self._pull(_merge_fingerprint(
+                            st, self._to_device(prov_pairs))))
             except Exception as e:  # a screen, never an error path
                 log.debug("merge fingerprint failed: %s", e)
                 self._fp_boundary = False
@@ -3211,7 +3267,8 @@ class LaneEngine:
                 kp = _geo_bucket(len(part), cap, min(64, cap))
                 idx = np.full(kp, self.n_lanes, np.int32)
                 idx[: len(part)] = part
-                st, rows = _retire_rows(st, jnp.asarray(idx), *floors)
+                st, rows = _retire_rows(st, self._to_device(idx),
+                                        *floors)
                 for arr in rows:
                     try:
                         arr.copy_to_host_async()
@@ -3308,7 +3365,7 @@ class LaneEngine:
                                                   retire_floors)
                 exported = []
                 for part, rows, floors, _t in chunks:
-                    rows_host = _unpack_rows(jax.device_get(rows),
+                    rows_host = _unpack_rows(self._pull(rows),
                                              *floors)
                     exported.extend(
                         self.materialize(rows_host, row, ctxs[lane])
@@ -3439,23 +3496,7 @@ class LaneEngine:
             )
 
             _SSP().bump(waves_packed=1, pack_members=len(members))
-        if self._rep_sh is not None:
-            # SPMD mode: code tensors (and the op tables) replicate
-            # across the mesh so the sharded dispatch sees consistent
-            # placements; memoized per code — engines persist across
-            # explores and must not re-broadcast every sweep
-            cc_r = self._cc_rep.get(code_bytes)
-            if cc_r is None:
-                cc_r = jax.tree_util.tree_map(
-                    lambda x: jax.device_put(x, self._rep_sh), cc)
-                self._cc_rep[code_bytes] = cc_r
-                self.exec_table = jax.device_put(self.exec_table,
-                                                 self._rep_sh)
-                self.taint_table = jax.device_put(self.taint_table,
-                                                  self._rep_sh)
-                self._resume_flag = jax.device_put(self._resume_flag,
-                                                   self._rep_sh)
-            cc = cc_r
+        cc = self._placed_code(code_bytes, cc)
         # per-byte-address coverage bitmap, device-resident across
         # windows AND explores of the same code (the interpreter's
         # execute_state coverage hook cannot see device steps; this is
@@ -3463,7 +3504,7 @@ class LaneEngine:
         visited = self._visited_dev.pop(code_bytes, None) \
             if not packed else None
         if visited is None:
-            visited = jnp.zeros(cc.packed.shape[0], bool)
+            visited = self._new_visited(cc)
         #: arena length drives the window-variant compile keys (the
         #: pow2 code buckets make packed and plain variants share)
         code_len = len(code_bytes) if not packed \
@@ -3539,7 +3580,7 @@ class LaneEngine:
                     self.stats["retire_overlap_ms"] += hidden_ms
                 _SS().bump(retire_overlap_ms=hidden_ms)
                 with trace.span("retire.pull", rows=len(items)):
-                    return _unpack_rows(jax.device_get(rows_ref),
+                    return _unpack_rows(self._pull(rows_ref),
                                         *floors)
 
             def build(rows_host):
@@ -3588,6 +3629,9 @@ class LaneEngine:
         pending_screen: List[tuple] = []
         screen_future = None
         screen_dead: List[int] = []
+        if self.mesh is not None:
+            _solver_stats.bump(mesh_sweeps=1)
+            _solver_stats.mesh_devices = int(self.mesh.devices.size)
         trace.begin("lane.explore", n_lanes=self.n_lanes,
                     entries=n_entries, code_len=code_len,
                     pack_members=len(members) if packed else 0)
@@ -3606,7 +3650,7 @@ class LaneEngine:
                         and full_bucket > small and warm_variant(
                     self.n_lanes, code_len, self.lane_kwargs,
                     self.window, self.step_budget,
-                    seed_bucket=full_bucket,
+                    seed_bucket=full_bucket, mesh=self.mesh,
                 ):
                     seed_cap = full_bucket
                 with trace.span("lane.seed_pack"):
@@ -3683,7 +3727,7 @@ class LaneEngine:
                 with trace.span("lane.window_pull"):
                     (misc, scal, utab, ftab, ridx, r_i32, r_u32,
                      r_u8, hidx, h_i32, h_u32, h_u8) = [
-                        np.asarray(x) for x in jax.device_get(out)]
+                        np.asarray(x) for x in self._pull(out)]
                 counts_h = {
                     "dlog_count": misc[:, 0], "status": misc[:, 1],
                     "steps": misc[:, 2], "sp": misc[:, 3],
@@ -3709,7 +3753,7 @@ class LaneEngine:
                     urb_big = min(urb_big, cap)
                     with trace.span("lane.escalate", log="records",
                                     rows=urb_big):
-                        utab, uc2 = jax.device_get(
+                        utab, uc2 = self._pull(
                             _unique_table_big(st, urb_big))
                     utab = np.asarray(utab)
                     ucount = int(uc2)
@@ -3720,7 +3764,7 @@ class LaneEngine:
                 if nf > ftab.shape[0]:
                     with trace.span("lane.escalate", log="forks",
                                     rows=nf):
-                        ftab = np.asarray(jax.device_get(
+                        ftab = np.asarray(self._pull(
                             _gather_full_flog(st)))
                 # decoding the window's record and fork tables is the
                 # first half of the drain; _drain_host below the second
@@ -3778,6 +3822,7 @@ class LaneEngine:
                     self.n_lanes, code_len,
                     self.lane_kwargs, self.window,
                     self.step_budget, seed_bucket=full_r,
+                    mesh=self.mesh,
                 ):
                     cap_r = full_r
                 held = held[:cap_r]
@@ -3916,7 +3961,7 @@ class LaneEngine:
                     for part, drows, dfloors, _t in dchunks:
                         with trace.span("retire.pull", rows=len(part)):
                             d_host = _unpack_rows(
-                                jax.device_get(drows), *dfloors)
+                                self._pull(drows), *dfloors)
                         _materialize_rows(part, d_host)
                 # 3. trivially-false lanes still RUNNING on device: kill
                 # them at the next dispatch (before it seeds anything) and
@@ -4029,11 +4074,11 @@ class LaneEngine:
                 if not packed:
                     self._visited_dev[code_bytes] = visited
                     self.visited_by_code[code_bytes] = np.asarray(
-                        jax.device_get(visited))[: cc.size]
+                        self._pull(visited))[: cc.size]
                 else:
                     # per-member coverage: slice each segment out of
                     # the arena bitmap and OR into the per-code map
-                    vh = np.asarray(jax.device_get(visited))
+                    vh = np.asarray(self._pull(visited))
                     for m in mems:
                         cur = vh[m.base: m.base + len(m.code)]
                         prev = self.visited_by_code.get(m.code)
@@ -4066,28 +4111,23 @@ class LaneEngine:
     # -- device-state pooling ------------------------------------------------
 
     def _shape_key(self) -> tuple:
-        mesh_key = None
-        if self.mesh is not None:
-            mesh_key = tuple(d.id for d in self.mesh.devices.flat)
-        return (self.n_lanes, mesh_key) \
+        return (self.n_lanes, _mesh_key(self.mesh)) \
             + tuple(sorted(self.lane_kwargs.items()))
 
     def _acquire_state(self) -> SymLaneState:
         pool = _STATE_POOL.get(self._shape_key())
         if pool:
             return pool.pop()
-        with trace.span("lane.init", n_lanes=self.n_lanes):
-            st = symstep.init_sym_lanes(self.n_lanes,
-                                        **self.lane_kwargs)
-            if self._lane_sh is not None:
-                st = jax.tree_util.tree_map(
-                    lambda x: jax.device_put(
-                        x, self._lane_sh
-                        if getattr(x, "ndim", 0) > 0
-                        and x.shape[0] == self.n_lanes
-                        else self._rep_sh),
-                    st)
-            return st
+        with trace.span("lane.init", n_lanes=self.n_lanes,
+                        shards=1 if self.mesh is None
+                        else self.mesh.devices.size):
+            if self.mesh is None:
+                return symstep.init_sym_lanes(self.n_lanes,
+                                              **self.lane_kwargs)
+            from ..parallel.mesh import init_planes
+
+            return init_planes(self.mesh, self.n_lanes,
+                               **self.lane_kwargs)
 
     def _release_state(self, st: SymLaneState) -> None:
         """Park the (all-DEAD) device buffers for the next explore —

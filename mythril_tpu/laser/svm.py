@@ -793,16 +793,19 @@ class LaserEVM:
                 width = min(width,
                             pick_width(args.tpu_lanes, len(states),
                                        headroom=4))
+            # each width warms on the mesh its engine will run on
             while width > 64 and not warm_variant(
                     width, len(code), {},
-                    DEFAULT_WINDOW, DEFAULT_STEP_BUDGET):
+                    DEFAULT_WINDOW, DEFAULT_STEP_BUDGET,
+                    mesh=pick_mesh(width)):
                 width //= 2
+            mesh = pick_mesh(width)
             if not warm_variant(width, len(code), {},
-                                DEFAULT_WINDOW, DEFAULT_STEP_BUDGET):
+                                DEFAULT_WINDOW, DEFAULT_STEP_BUDGET,
+                                mesh=mesh):
                 self.work_list.extend(states)
                 trace.end("svm.sweep_prep")
                 continue
-            mesh = pick_mesh(width)
             key = (code, width,
                    mesh.devices.size if mesh is not None else 0,
                    frozenset(blocked),

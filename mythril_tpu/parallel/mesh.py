@@ -1,22 +1,28 @@
-"""Device-mesh lane sharding: SPMD path exploration across TPU chips.
+"""Device-mesh lane sharding: one exploration's lanes over several chips.
 
-The reference is single-process and parallelizes contract analysis by
-launching many OS processes (tests/integration_tests/parallel_test.py:8-16
-in /root/reference). This module is the TPU-native replacement promised by
-SURVEY.md §2.10 (contract-level + distributed-backend rows): the lane batch
-(ops/stepper.LaneState) is sharded over a 1-D `lanes` axis of a
-jax.sharding.Mesh, the stepper loop runs per-device inside shard_map
-(no cross-chip traffic in the data-parallel stepping itself — the
-stepper's op-family gates reduce over the local shard only), and the few
-global decisions (how many lanes are live, when to rebalance/compact)
-ride ICI collectives (psum/all_gather) inside shard_map.
+What the lane engine runs (laser/lane_engine.py): `pick_mesh` resolves
+the `--tpu-mesh` policy to a 1-D `lanes` mesh (`make_mesh`), and a
+meshed `LaneEngine` runs the SAME jitted programs as on one device
+(`_window_exec`, the retire and table gathers) under GSPMD. The lane
+planes are born sharded (`init_planes`: each chip materialises only its
+own rows), the code tensors and the packed host buffers are replicated
+(`replicated`), and the collectives are GSPMD's own: the ones XLA
+inserts where a window's cumsum/scatter/sort phases read across lanes.
+The host pulls (window logs, retired rows, the coverage bitmap) gather
+from every chip; each is a `mesh.pull` span with its bytes.
 
-Multi-host corpus sharding (one contract set per host over DCN) composes on
-top: each host builds its own mesh over local devices and runs an
-independent corpus shard; nothing in this module assumes a single process.
+The `shard_map` helpers below (`shard_lanes`, `replicate_code`,
+`sharded_run`, `live_lane_counts`, `compact_lanes`, `steal_balance`)
+drive the concrete stepper (ops/stepper) with per-device loops; no
+engine path calls them, only tests/test_parallel.py does.
+
+Multi-host corpus sharding (one contract set per host over DCN) composes
+on top: each host builds its own mesh over local devices and runs an
+independent corpus shard; nothing in this module assumes a single
+process.
 """
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional
 
 import jax
@@ -27,7 +33,7 @@ from jax import lax
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops import stepper
+from ..ops import stepper, symstep
 from ..ops.stepper import CompiledCode, LaneState, Status
 
 LANES_AXIS = "lanes"
@@ -48,6 +54,45 @@ def lane_sharding(mesh: Mesh) -> NamedSharding:
 
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
+
+
+#: SymLaneState fields of n_lanes rows that are not lane planes: the
+#: free-slot stack holds slot numbers, and every window program returns
+#: it replicated, so it is built replicated too
+_NOT_PLANES = frozenset({"free_slots"})
+
+
+def plane_shardings(mesh: Mesh, n_lanes: int, **lane_kwargs):
+    """The placement of every SymLaneState leaf on the mesh: a lane
+    plane (leading axis n_lanes) splits its rows over `lanes`; every
+    other leaf is replicated. The window programs return the same
+    placement, so a pooled state and a fresh one share one compile."""
+    shapes = jax.eval_shape(
+        partial(symstep.init_sym_lanes, n_lanes, **lane_kwargs))
+    lanes, rep = lane_sharding(mesh), replicated(mesh)
+    return type(shapes)(*(
+        lanes if name not in _NOT_PLANES and s.ndim
+        and s.shape[0] == n_lanes else rep
+        for name, s in zip(shapes._fields, shapes)))
+
+
+@lru_cache(maxsize=None)
+def _plane_init(mesh: Mesh, n_lanes: int, lane_kwargs: tuple):
+    kwargs = dict(lane_kwargs)
+
+    def sharded_lane_planes():
+        return symstep.init_sym_lanes(n_lanes, **kwargs)
+
+    return jax.jit(sharded_lane_planes, out_shardings=plane_shardings(
+        mesh, n_lanes, **kwargs))
+
+
+def init_planes(mesh: Mesh, n_lanes: int, **lane_kwargs):
+    """symstep.init_sym_lanes built sharded: one jitted program whose
+    outputs are placed by `plane_shardings`, so each chip materialises
+    only its own rows and no whole plane ever exists on one chip."""
+    return _plane_init(mesh, n_lanes,
+                          tuple(sorted(lane_kwargs.items())))()
 
 
 def shard_lanes(state: LaneState, mesh: Mesh) -> LaneState:

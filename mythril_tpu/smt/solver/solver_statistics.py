@@ -173,6 +173,11 @@ class SolverStatistics(object, metaclass=Singleton):
         self.lane_windows = 0         # fused window dispatches issued
         #                               (the denominator the packed
         #                               bench gate compares)
+        # lane mesh (parallel/mesh.py, docs/observability.md)
+        self.mesh_sweeps = 0          # lane sweeps run on a mesh
+        self.mesh_devices = 0         # devices of the last mesh (gauge)
+        self.mesh_pull_bytes = 0      # bytes pulled to the host from
+        #                               arrays on a mesh (mesh.pull)
         self.mat_pool_reuses = 0      # K>=2 retire rings that reused
         #                               the process-wide worker pool
         #                               instead of spawning threads
@@ -329,6 +334,9 @@ class SolverStatistics(object, metaclass=Singleton):
             "dispatches_saved": self.dispatches_saved,
             "lane_windows": self.lane_windows,
             "mat_pool_reuses": self.mat_pool_reuses,
+            "mesh_sweeps": self.mesh_sweeps,
+            "mesh_devices": self.mesh_devices,
+            "mesh_pull_bytes": self.mesh_pull_bytes,
             "codec_bytes_raw": self.codec_bytes_raw,
             "codec_bytes_encoded": self.codec_bytes_encoded,
             "codec_ref_hits": self.codec_ref_hits,

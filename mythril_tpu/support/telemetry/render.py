@@ -106,6 +106,11 @@ GROUPS: Sequence[Tuple[str, str, Gate, Tuple[Tuple[str, str], ...]]] = (
         ("spill_merged", "spill_merged_lanes"),
         ("ring_high_water", "ring_high_water"),
     )),
+    ("Lane mesh", "docs/observability.md", ("mesh_sweeps",), (
+        ("sweeps", "mesh_sweeps"),
+        ("devices", "mesh_devices"),
+        ("pull_bytes", "mesh_pull_bytes"),
+    )),
     ("State codec", "docs/state_codec.md",
      ("codec_bytes_raw", "codec_bytes_encoded", "codec_ref_hits",
       "codec_drop_whole"), (
